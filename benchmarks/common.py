@@ -11,14 +11,17 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import pickle
+import subprocess
 import sys
 import time
 
 import numpy as np
 
+import repro.core
 from repro.core.cluster_index import ClusterIndex
 from repro.core.flat import exact_topk
 from repro.core.graph_index import GraphIndex
@@ -54,8 +57,36 @@ def bench_dataset(name: str) -> DatasetSpec:
     return base[name]
 
 
+def _source_files(pkg: str) -> list[str]:
+    """The package's source files that git tracks (every file under it
+    where the tree is not a git checkout)."""
+    try:
+        out = subprocess.run(["git", "ls-files", "-z", "--", "."], cwd=pkg,
+                             capture_output=True, check=True).stdout
+        return sorted(f for f in out.decode().split("\0") if f)
+    except (OSError, subprocess.CalledProcessError):
+        return sorted(
+            os.path.relpath(os.path.join(d, f), pkg)
+            for d, dirs, files in os.walk(pkg)
+            if "__pycache__" not in d for f in files)
+
+
+@functools.cache
+def _source_digest() -> str:
+    """Hash of the ``repro`` sources: a cached build is reused only by the
+    code that made it."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(
+        repro.core.__file__)))
+    h = hashlib.sha256()
+    for rel in _source_files(pkg):
+        h.update(rel.encode())
+        with open(os.path.join(pkg, rel), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _key(*parts) -> str:
-    raw = repr(parts).encode()
+    raw = repr((_source_digest(),) + parts).encode()
     return hashlib.sha256(raw).hexdigest()[:24]
 
 

@@ -136,7 +136,9 @@ def bench_pricing() -> dict:
         _check(f"kernel-pricing-amortizes-d{dim}", bulk < solo,
                f"dim={dim} unit cost {solo:.3e}s/comp at batch 1 vs "
                f"{bulk:.3e} at batch 1e5 (want batching cheaper)")
-    frac = max(r["roofline_frac"] for r in table.meta["rooflines"])
+    # a table measured off the TPU records no roofline share
+    frac = max((r["roofline_frac"] for r in table.meta["rooflines"]),
+               default=0.0)
     _check("kernel-pricing-roofline-sane", frac < 1.0,
            f"max measured roofline fraction {frac:.2e} (want < 1)")
     return dict(table_entries=len(table.entries),

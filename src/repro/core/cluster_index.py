@@ -276,12 +276,12 @@ def device_search_batch(
                  )(queries, vecs)                        # (B, np*ml)
     ids = ids.reshape(B, -1)
     d = jnp.where(ids < 0, jnp.inf, d)
-    # dedup replicas: a duplicated id appears with identical distance; k-NN
-    # sets are computed on unique ids via a small penalty-free pass: sort by
-    # distance and mask repeated ids within the top window.
-    dd, ii = jax.lax.top_k(-d, min(4 * k, d.shape[-1]))
+    # dedup replicas: mask repeated ids within the top window.  A point
+    # sits at most once in each list, so it has at most ``nprobe`` copies
+    # among the candidates and the first k*nprobe hold k unique ids.
+    dd, ii = jax.lax.top_k(-d, min(k * nprobe, d.shape[-1]))
     dd = -dd
-    cand_ids = jnp.take_along_axis(ids, ii, axis=1)      # (B, 4k)
+    cand_ids = jnp.take_along_axis(ids, ii, axis=1)      # (B, k*np)
     same = cand_ids[:, :, None] == cand_ids[:, None, :]
     earlier = jnp.tril(jnp.ones(same.shape[-2:], bool), k=-1)[None]
     dup = jnp.any(same & earlier, axis=-1)

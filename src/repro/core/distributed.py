@@ -22,9 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.core.distances import pairwise_sq_l2, topk_smallest
+from repro.core.distances import F32_DOT, pairwise_sq_l2, topk_smallest
 
 
 def _all_axes(mesh) -> tuple:
@@ -60,9 +59,11 @@ def sharded_search_step(mesh, *, nprobe_local: int, k: int):
         B = q.shape[0]
         qf = q.astype(jnp.float32)
         qn = jnp.sum(qf * qf, axis=-1, keepdims=True)    # (B, 1)
+        int8 = pv.dtype == jnp.int8
         ip = jax.lax.dot_general(
             q, pv, (((1,), (3,)), ((0,), (0,))),
-            preferred_element_type=(jnp.int32 if pv.dtype == jnp.int8
+            precision=None if int8 else F32_DOT,
+            preferred_element_type=(jnp.int32 if int8
                                     else jnp.float32))   # (B, np, M)
         d = qn + pn - 2.0 * ip.reshape(B, -1).astype(jnp.float32)
         d = jnp.where(pi < 0, jnp.inf, d)
@@ -78,11 +79,11 @@ def sharded_search_step(mesh, *, nprobe_local: int, k: int):
         gids = jnp.take_along_axis(ai, gsel, axis=1)
         return gids, gvals
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(shard_spec, shard_spec, shard_spec, shard_spec, P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return fn
 
 
@@ -105,9 +106,9 @@ def sharded_kmeans_step(mesh):
         return jnp.where(counts[:, None] > 0,
                          sums / jnp.maximum(counts, 1.0)[:, None], cent)
 
-    return shard_map(step, mesh=mesh,
-                     in_specs=(P(axes), P()), out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(P(axes), P()), out_specs=P(),
+                         check_vma=False)
 
 
 # --------------------------------------------------------- dry-run cell --

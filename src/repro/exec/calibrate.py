@@ -9,10 +9,11 @@ container the backend is Pallas interpret / XLA:CPU; on a TPU the same
 calls compile to Mosaic and the measured numbers change accordingly —
 which is the point: pricing follows the hardware, not hand-set constants.
 
-Each dist point is cross-checked against the roofline model
-(:data:`repro.launch.roofline.HW`): achieved FLOP/s above the hardware
-peak would mean the timer is lying, so that fails loudly; the achieved
-fraction is recorded in the table meta either way.
+On a TPU each dist point is cross-checked against the chip's published
+peaks (:mod:`repro.exec.peaks`, keyed by ``device_kind``): a point
+faster than its roofline would mean the timer is lying, so that fails
+loudly; the roofline share is recorded in the table meta either way.  Off
+the TPU no roofline share is recorded.
 
 CLI::
 
@@ -63,8 +64,13 @@ def _time(fn, iters: int, warmup: int) -> float:
 
 
 def measure_table(quick: bool = False, *, iters: int | None = None,
-                  seed: int = 0, verbose: bool = False) -> CalibrationTable:
-    """Run the measurement grid and build a :class:`CalibrationTable`."""
+                  seed: int = 0, verbose: bool = False,
+                  interpret: bool | None = None) -> CalibrationTable:
+    """Run the measurement grid and build a :class:`CalibrationTable`.
+
+    ``interpret`` is passed to every kernel call (``None`` auto-detects:
+    the Pallas interpreter off the TPU).
+    """
     iters = iters or (2 if quick else 5)
     warmup = 1 if quick else 2
     dims = DIMS_QUICK if quick else DIMS
@@ -74,30 +80,38 @@ def measure_table(quick: bool = False, *, iters: int | None = None,
     rng = np.random.default_rng(seed)
 
     import jax
-    from repro.launch.roofline import HW
+    from repro.exec.peaks import device_peaks
 
+    device = jax.devices()[0]
+    peaks = device_peaks(device)
     entries: list[CalibEntry] = []
     rooflines: list[dict] = []
     for dim in dims:
         for bq, n in dist_points:
             q = rng.standard_normal((bq, dim)).astype(np.float32)
             x = rng.standard_normal((n, dim)).astype(np.float32)
-            sec = _time(lambda: batched_topk(q, x, TOPK), iters, warmup)
+            sec = _time(lambda: batched_topk(q, x, TOPK,
+                                             interpret=interpret),
+                        iters, warmup)
             pairs = bq * n
-            unit_s = sec / pairs
             achieved = 2.0 * dim * pairs / sec
-            frac = achieved / HW["peak_flops"]
-            if frac > 1.0:
-                raise RuntimeError(
-                    f"calibration point dim={dim} pairs={pairs} measured "
-                    f"{achieved:.3e} FLOP/s above the roofline peak "
-                    f"{HW['peak_flops']:.3e} — timer is broken")
             entries.append(CalibEntry(
                 op="dist", dim=dim, pq_m=0, batch=pairs, dtype="float32",
-                unit_s=unit_s, us_per_call=sec * 1e6))
-            rooflines.append(dict(dim=dim, batch=pairs,
-                                  achieved_gflops=round(achieved / 1e9, 3),
-                                  roofline_frac=round(frac, 9)))
+                unit_s=sec / pairs, us_per_call=sec * 1e6))
+            if peaks is not None:
+                hbm_bytes = 4 * (bq + n) * dim + 8 * bq * TOPK
+                floor_s = max(2.0 * dim * pairs / peaks["bf16_flops"],
+                              hbm_bytes / peaks["hbm_Bps"])
+                frac = floor_s / sec
+                if frac > 1.0:
+                    raise RuntimeError(
+                        f"calibration point dim={dim} pairs={pairs} took "
+                        f"{sec:.3e}s, under the roofline floor "
+                        f"{floor_s:.3e}s of {device.device_kind} — timer "
+                        f"is broken")
+                rooflines.append(dict(dim=dim, batch=pairs,
+                                      achieved_gflops=achieved / 1e9,
+                                      roofline_frac=frac))
             if verbose:
                 print(f"  dist dim={dim:<4} pairs={pairs:<6} "
                       f"{sec * 1e6:9.1f} us/call  "
@@ -107,7 +121,8 @@ def measure_table(quick: bool = False, *, iters: int | None = None,
             codes = rng.integers(0, 256, (n, m), dtype=np.uint8)
             table = rng.standard_normal((m, 256)).astype(np.float32)
             sec = _time(
-                lambda: np.asarray(ops.adc_lookup(codes, table)),
+                lambda: np.asarray(ops.adc_lookup(codes, table,
+                                                  interpret=interpret)),
                 iters, warmup)
             lookups = n * m
             entries.append(CalibEntry(
@@ -117,8 +132,10 @@ def measure_table(quick: bool = False, *, iters: int | None = None,
                 print(f"  adc  m={m:<6} codes={n:<6} "
                       f"{sec * 1e6:9.1f} us/call", file=sys.stderr)
 
-    meta = dict(backend=jax.default_backend(),
-                interpret=ops.default_interpret(),
+    meta = dict(backend=device.platform,
+                device_kind=device.device_kind,
+                interpret=(ops.default_interpret() if interpret is None
+                           else bool(interpret)),
                 jax=jax.__version__,
                 quick=bool(quick), iters=iters, topk=TOPK,
                 rooflines=rooflines,
@@ -154,4 +171,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

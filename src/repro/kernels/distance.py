@@ -43,8 +43,11 @@ def _dist_kernel(q_ref, x_ref, o_ref, *, acc_dtype):
         xa = x.astype(acc_dtype)
     qn = jnp.sum(qa * qa, axis=-1)[:, None]      # (BQ, 1)
     xn = jnp.sum(xa * xa, axis=-1)[None, :]      # (1, BN)
+    # f32 at HIGHEST: the TPU default would round f32 operands to bf16
     ip = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=(None if acc_dtype == jnp.int32
+                   else jax.lax.Precision.HIGHEST),
         preferred_element_type=acc_dtype)        # (BQ, BN) on the MXU
     o_ref[...] += qn + xn - 2 * ip
 

@@ -38,6 +38,7 @@ def _fused_kernel(q_ref, x_ref, vals_ref, ids_ref, *, k, bn, n_total):
     qn = jnp.sum(q * q, axis=-1)[:, None]
     xn = jnp.sum(x * x, axis=-1)[None, :]
     ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     d = jnp.maximum(qn + xn - 2.0 * ip, 0.0)      # (BQ, BN)
 

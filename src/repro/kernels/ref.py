@@ -24,6 +24,7 @@ def l2_distance_ref(q: jax.Array, x: jax.Array) -> jax.Array:
     qn = jnp.sum(qf * qf, axis=-1)[:, None]
     xn = jnp.sum(xf * xf, axis=-1)[None, :]
     ip = jax.lax.dot_general(qf, xf, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     return jnp.maximum(qn + xn - 2.0 * ip, 0.0)
 

@@ -90,6 +90,26 @@ def test_device_search_matches_host(built):
         assert recall_at_k(ids[i], gt[i]) >= 0.7
 
 
+def test_device_search_dedups_points_replicated_in_every_probe():
+    # 12 points sit in all 8 lists: the best 10 unique ids lie 80 deep in
+    # the candidates, past a window of 4k
+    L, ml, D, npt = 8, 16, 4, 12
+    pts = np.zeros((npt, D), np.float32)
+    pts[:, 0] = 0.1 * (np.arange(npt) + 1)
+    vecs = np.zeros((L, ml, D), np.float32)
+    vecs[:, :npt] = pts
+    ids = np.full((L, ml), -1, np.int32)
+    ids[:, :npt] = np.arange(npt)
+    cents = np.random.default_rng(0).standard_normal((L, D)).astype(
+        np.float32)
+    got, vals = device_search_batch(
+        jnp.asarray(cents), jnp.asarray(vecs), jnp.asarray(ids),
+        jnp.zeros((1, D), jnp.float32), nprobe=L, k=10)
+    np.testing.assert_array_equal(np.asarray(got)[0], np.arange(10))
+    np.testing.assert_allclose(np.asarray(vals)[0], (pts[:10, 0]) ** 2,
+                               rtol=1e-5)
+
+
 def test_int8_dataset_build_and_search():
     from repro.data.synth import MSSPACE_ANALOG
     spec = scaled(MSSPACE_ANALOG, 1500, 10)

@@ -393,6 +393,23 @@ def test_calibrate_quick_produces_usable_table(tmp_path):
     assert CalibrationTable.load(str(p)).dist_unit_s(32) > 0
 
 
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", None),
+    ("tpu", "TPU v5 lite", dict(bf16_flops=197e12, hbm_Bps=819e9)),
+])
+def test_device_peaks_by_kind(platform, kind, want):
+    from repro.exec.peaks import device_peaks
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    assert device_peaks(dev) == want
+
+
+def test_device_peaks_refuses_unknown_tpu_kind():
+    from repro.exec.peaks import device_peaks
+    dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(KeyError, match="TPU v99"):
+        device_peaks(dev)
+
+
 # ------------------------------------------------------- window tuning --
 
 def test_tune_batch_window_smoke():
