@@ -1,0 +1,2 @@
+"""Host time per batch beyond the device search (bulk cells)."""
+from chipbench.readings import host_ms_per_batch as read  # noqa: F401
