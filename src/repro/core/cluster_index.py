@@ -33,6 +33,13 @@ from repro.core.types import (ClusterIndexParams, FetchBatch, FetchRequest,
 from repro.storage.object_store import ObjectStore
 
 
+#: the stages of :func:`device_search_batch`, in order: each is a
+#: ``jax.named_scope``, so every op of the compiled search carries its
+#: stage as the first scope of its ``op_name``
+SEARCH_STAGES = ("probe", "gather", "scan", "select")
+PROBE, GATHER, SCAN, SELECT = SEARCH_STAGES
+
+
 @dataclasses.dataclass
 class ClusterIndexMeta:
     """Compute-node-resident metadata (what TurboPuffer caches, §2.1)."""
@@ -265,27 +272,35 @@ def device_search_batch(
 
     One fused pipeline: centroid matmul -> top-nprobe -> posting-list gather
     -> masked distance -> global top-k.  This is the MXU-native equivalent
-    of the paper's fetch-then-scan; "fetch" becomes an HBM gather.
+    of the paper's fetch-then-scan; "fetch" becomes an HBM gather.  Each
+    step runs under its name in :data:`SEARCH_STAGES` (the dedup as
+    ``select/dedup``); the scopes change only the ops' metadata.
     """
     B = queries.shape[0]
-    cd = pairwise_sq_l2(queries, centroids)              # (B, L)
-    _, probe = topk_smallest(cd, nprobe)                 # (B, nprobe)
-    vecs = list_vecs[probe]                              # (B, np, ml, D)
-    ids = list_ids[probe]                                # (B, np, ml)
-    d = jax.vmap(lambda qv, vv: pairwise_sq_l2(qv[None], vv.reshape(-1, vv.shape[-1]))[0]
-                 )(queries, vecs)                        # (B, np*ml)
-    ids = ids.reshape(B, -1)
-    d = jnp.where(ids < 0, jnp.inf, d)
-    # dedup replicas: mask repeated ids within the top window.  A point
-    # sits at most once in each list, so it has at most ``nprobe`` copies
-    # among the candidates and the first k*nprobe hold k unique ids.
-    dd, ii = jax.lax.top_k(-d, min(k * nprobe, d.shape[-1]))
-    dd = -dd
-    cand_ids = jnp.take_along_axis(ids, ii, axis=1)      # (B, k*np)
-    same = cand_ids[:, :, None] == cand_ids[:, None, :]
-    earlier = jnp.tril(jnp.ones(same.shape[-2:], bool), k=-1)[None]
-    dup = jnp.any(same & earlier, axis=-1)
-    dd = jnp.where(dup, jnp.inf, dd)
-    vals, sel = topk_smallest(dd, k)
-    out_ids = jnp.take_along_axis(cand_ids, sel, axis=1)
+    with jax.named_scope(PROBE):
+        cd = pairwise_sq_l2(queries, centroids)          # (B, L)
+        _, probe = topk_smallest(cd, nprobe)             # (B, nprobe)
+    with jax.named_scope(GATHER):
+        vecs = list_vecs[probe]                          # (B, np, ml, D)
+        ids = list_ids[probe]                            # (B, np, ml)
+    with jax.named_scope(SCAN):
+        d = jax.vmap(lambda qv, vv: pairwise_sq_l2(qv[None], vv.reshape(-1, vv.shape[-1]))[0]
+                     )(queries, vecs)                    # (B, np*ml)
+        ids = ids.reshape(B, -1)
+        d = jnp.where(ids < 0, jnp.inf, d)
+    with jax.named_scope(SELECT):
+        # dedup replicas: mask repeated ids within the top window.  A point
+        # sits at most once in each list, so it has at most ``nprobe``
+        # copies among the candidates and the first k*nprobe hold k unique
+        # ids.
+        dd, ii = jax.lax.top_k(-d, min(k * nprobe, d.shape[-1]))
+        dd = -dd
+        cand_ids = jnp.take_along_axis(ids, ii, axis=1)  # (B, k*np)
+        with jax.named_scope("dedup"):
+            same = cand_ids[:, :, None] == cand_ids[:, None, :]
+            earlier = jnp.tril(jnp.ones(same.shape[-2:], bool), k=-1)[None]
+            dup = jnp.any(same & earlier, axis=-1)
+            dd = jnp.where(dup, jnp.inf, dd)
+        vals, sel = topk_smallest(dd, k)
+        out_ids = jnp.take_along_axis(cand_ids, sel, axis=1)
     return out_ids, vals

@@ -23,7 +23,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.cluster_index import GATHER, PROBE, SCAN, SELECT
 from repro.core.distances import F32_DOT, pairwise_sq_l2, topk_smallest
+
+#: the stage after the local search's: the all-gather of every shard's
+#: top-k and the global top-k over them, a ``jax.named_scope`` as well
+MERGE = "merge"
 
 
 def _all_axes(mesh) -> tuple:
@@ -37,6 +42,9 @@ def sharded_search_step(mesh, *, nprobe_local: int, k: int):
       centroids  (L, D) f32,  list_vecs (L, M, D),  list_ids (L, M) i32,
       queries    (B, D) replicated.
     Returns fn(centroids, list_vecs, list_ids, queries) -> (ids, dists).
+    Each shard's search runs under the scopes of
+    :data:`repro.core.cluster_index.SEARCH_STAGES`, the merge under
+    :data:`MERGE`.
     """
     axes = _all_axes(mesh)
     shard_spec = P(axes)
@@ -47,36 +55,42 @@ def sharded_search_step(mesh, *, nprobe_local: int, k: int):
         # scalars — the gathered vectors are read exactly once, by the
         # int8 MXU dot (§Perf vector-search iteration 1: the baseline
         # recomputed ||x||^2 from the gathered vectors, ~2x the bytes).
-        d_c = pairwise_sq_l2(q, cent)                    # (B, L_loc)
-        # NOTE (§Perf iteration 2, refuted on this artifact): lowering
-        # this top-k through jax.lax.approx_min_k measured +9% HBO bytes
-        # on the CPU dry-run artifact (sort fallback); on real TPU it
-        # lowers to PartialReduce and is the right choice — revisit there.
-        _, probe = topk_smallest(d_c, nprobe_local)      # (B, np)
-        pv = vecs[probe]                                 # (B, np, M, D)
-        pi = ids[probe].reshape(q.shape[0], -1)          # (B, np*M)
-        pn = norms[probe].reshape(q.shape[0], -1)        # (B, np*M) f32
         B = q.shape[0]
-        qf = q.astype(jnp.float32)
-        qn = jnp.sum(qf * qf, axis=-1, keepdims=True)    # (B, 1)
-        int8 = pv.dtype == jnp.int8
-        ip = jax.lax.dot_general(
-            q, pv, (((1,), (3,)), ((0,), (0,))),
-            precision=None if int8 else F32_DOT,
-            preferred_element_type=(jnp.int32 if int8
-                                    else jnp.float32))   # (B, np, M)
-        d = qn + pn - 2.0 * ip.reshape(B, -1).astype(jnp.float32)
-        d = jnp.where(pi < 0, jnp.inf, d)
-        vals, sel = topk_smallest(d, k)                  # (B, k) local
-        out_ids = jnp.take_along_axis(pi, sel, axis=1)
+        with jax.named_scope(PROBE):
+            d_c = pairwise_sq_l2(q, cent)                # (B, L_loc)
+            # NOTE (§Perf iteration 2, refuted on this artifact): lowering
+            # this top-k through jax.lax.approx_min_k measured +9% HBO
+            # bytes on the CPU dry-run artifact (sort fallback); on real
+            # TPU it lowers to PartialReduce and is the right choice —
+            # revisit there.
+            _, probe = topk_smallest(d_c, nprobe_local)  # (B, np)
+        with jax.named_scope(GATHER):
+            pv = vecs[probe]                             # (B, np, M, D)
+            pi = ids[probe].reshape(B, -1)               # (B, np*M)
+            pn = norms[probe].reshape(B, -1)             # (B, np*M) f32
+        with jax.named_scope(SCAN):
+            qf = q.astype(jnp.float32)
+            qn = jnp.sum(qf * qf, axis=-1, keepdims=True)  # (B, 1)
+            int8 = pv.dtype == jnp.int8
+            ip = jax.lax.dot_general(
+                q, pv, (((1,), (3,)), ((0,), (0,))),
+                precision=None if int8 else F32_DOT,
+                preferred_element_type=(jnp.int32 if int8
+                                        else jnp.float32))  # (B, np, M)
+            d = qn + pn - 2.0 * ip.reshape(B, -1).astype(jnp.float32)
+            d = jnp.where(pi < 0, jnp.inf, d)
+        with jax.named_scope(SELECT):
+            vals, sel = topk_smallest(d, k)              # (B, k) local
+            out_ids = jnp.take_along_axis(pi, sel, axis=1)
         # merge across every shard: one small all-gather
-        av = jax.lax.all_gather(vals, axes, tiled=False)   # (S, B, k)
-        ai = jax.lax.all_gather(out_ids, axes, tiled=False)
-        S = av.shape[0]
-        av = av.transpose(1, 0, 2).reshape(B, S * k)
-        ai = ai.transpose(1, 0, 2).reshape(B, S * k)
-        gvals, gsel = topk_smallest(av, k)
-        gids = jnp.take_along_axis(ai, gsel, axis=1)
+        with jax.named_scope(MERGE):
+            av = jax.lax.all_gather(vals, axes, tiled=False)   # (S, B, k)
+            ai = jax.lax.all_gather(out_ids, axes, tiled=False)
+            S = av.shape[0]
+            av = av.transpose(1, 0, 2).reshape(B, S * k)
+            ai = ai.transpose(1, 0, 2).reshape(B, S * k)
+            gvals, gsel = topk_smallest(av, k)
+            gids = jnp.take_along_axis(ai, gsel, axis=1)
         return gids, gvals
 
     fn = jax.shard_map(

@@ -4,12 +4,16 @@ The production-scale version is exercised by the dry-run (512 fake
 devices); here the same shard_map code runs on a 1-device mesh and must
 match flat exact search on the probed set.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.distributed import sharded_kmeans_step, sharded_search_step
+from repro.core.cluster_index import SEARCH_STAGES
+from repro.core.distributed import (MERGE, sharded_kmeans_step,
+                                    sharded_search_step)
 from repro.core.flat import exact_topk
 
 
@@ -42,6 +46,21 @@ def test_sharded_search_matches_flat(mesh):
     for b in range(B):
         assert len(np.intersect1d(np.asarray(got_ids)[b],
                                   want_ids[b])) >= 4
+
+
+def test_sharded_search_names_its_stages(mesh):
+    """The compiled step carries each shard's search stages and the merge
+    as the first scope of its ops' ``op_name``."""
+    L, M, D, B = 32, 4, 8, 2
+    fn = jax.jit(sharded_search_step(mesh, nprobe_local=4, k=3))
+    shapes = [jax.ShapeDtypeStruct(s, t) for s, t in (
+        ((L, D), jnp.float32), ((L, M, D), jnp.float32), ((L, M), jnp.int32),
+        ((L, M), jnp.float32), ((B, D), jnp.float32))]
+    with mesh:
+        text = fn.lower(*shapes).compile().as_text()
+    scopes = {name.split("/")[1] for name in
+              re.findall(r'op_name="(jit\([^"/]*\)/[^"]*/[^"]*)"', text)}
+    assert scopes == set(SEARCH_STAGES) | {MERGE}
 
 
 def test_sharded_search_respects_nprobe(mesh):

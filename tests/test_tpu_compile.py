@@ -8,13 +8,14 @@ results.  The topology is described inside a fixture, never at import: a
 process that loads the TPU library holds it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.core.cluster_index import device_search_batch
+from repro.core.cluster_index import SEARCH_STAGES, device_search_batch
 from repro.core.distributed import sharded_search_step
 from repro.core.pq import default_pq_dims
 from repro.kernels import distance, fused_topk, pq_adc
@@ -96,6 +97,32 @@ def test_device_search_batch_compiles_at_deep_1m(one_chip):
                  nprobe=32, k=10).compile()
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _first_scopes(hlo_text: str) -> list:
+    """For every ``op_name`` of the form ``jit(...)/...``, the element after
+    the ``jit(...)``, or None where a primitive's name is all that follows."""
+    out = []
+    for op_name in re.findall(r'op_name="(jit\([^"]*)"', hlo_text):
+        parts = op_name.split("/")
+        out.append(parts[1] if len(parts) > 2 else None)
+    return out
+
+
+@pytest.mark.parametrize("L,maxlen,D,B", [(12_500, 1_536, 96, 256),
+                                          (1_000, 1_792, 960, 16)],
+                         ids=["deep96-f32", "gist960-f32"])
+def test_device_search_batch_names_its_stages(one_chip, L, maxlen, D, B):
+    # the layouts of the chip benchmark's cells, at their batch sizes: every
+    # op the TPU compiler keeps metadata for is in one of the four stages
+    fn = jax.jit(device_search_batch, static_argnames=("nprobe", "k"))
+    text = fn.lower(_sds((L, D), jnp.float32, one_chip),
+                    _sds((L, maxlen, D), jnp.float32, one_chip),
+                    _sds((L, maxlen), jnp.int32, one_chip),
+                    _sds((B, D), jnp.float32, one_chip),
+                    nprobe=32, k=10).compile().as_text()
+    scopes = set(_first_scopes(text))
+    assert scopes == set(SEARCH_STAGES), sorted(map(str, scopes))
 
 
 @pytest.mark.parametrize("nprobe_local,batch", [(16, 64), (976, 4)])
