@@ -30,6 +30,7 @@ from repro.core import kmeans as km
 from repro.core.distances import np_sq_l2, pairwise_sq_l2, topk_smallest
 from repro.core.types import (ClusterIndexParams, FetchBatch, FetchRequest,
                               QueryMetrics, SearchParams, SearchResult)
+from repro.kernels import ops as kernel_ops
 from repro.storage.object_store import ObjectStore
 
 
@@ -243,10 +244,13 @@ class ClusterIndex:
 
         Returns centroids (L, D), list_vecs (L, maxlen, D),
         list_ids (L, maxlen) int32 (-1 pad), list_len (L,) int32.
+        ``maxlen`` defaults to the longest list rounded up to a multiple of
+        128, so that TPU lays the slots on the lanes and the list scan
+        reads each list where it lies.
         """
         L = self.meta.n_lists
         dim = self.meta.dim
-        ml = int(max_len or self.meta.list_lengths.max())
+        ml = int(max_len or -(-self.meta.list_lengths.max() // 128) * 128)
         vecs = np.zeros((L, ml, dim), dtype=np.float32)
         ids = np.full((L, ml), -1, dtype=np.int32)
         for li in range(L):
@@ -270,24 +274,24 @@ def device_search_batch(
 ) -> tuple[jax.Array, jax.Array]:
     """Resident-array batched cluster search (pjit/TPU path).
 
-    One fused pipeline: centroid matmul -> top-nprobe -> posting-list gather
-    -> masked distance -> global top-k.  This is the MXU-native equivalent
-    of the paper's fetch-then-scan; "fetch" becomes an HBM gather.  Each
-    step runs under its name in :data:`SEARCH_STAGES` (the dedup as
-    ``select/dedup``); the scopes change only the ops' metadata.
+    One pipeline: centroid matmul -> top-nprobe -> the probed lists' ids ->
+    the list-scan kernel's distances, masked at padding -> global top-k.
+    This is the device equivalent of the paper's fetch-then-scan: "fetch"
+    is the kernel's DMA of each probed list's real chunks from where it
+    lies in HBM (``repro.kernels.list_scan``).  Each step runs under its
+    name in :data:`SEARCH_STAGES` (the dedup as ``select/dedup``); the
+    scopes change only the ops' metadata.
     """
     B = queries.shape[0]
     with jax.named_scope(PROBE):
         cd = pairwise_sq_l2(queries, centroids)          # (B, L)
         _, probe = topk_smallest(cd, nprobe)             # (B, nprobe)
     with jax.named_scope(GATHER):
-        vecs = list_vecs[probe]                          # (B, np, ml, D)
         ids = list_ids[probe]                            # (B, np, ml)
     with jax.named_scope(SCAN):
-        d = jax.vmap(lambda qv, vv: pairwise_sq_l2(qv[None], vv.reshape(-1, vv.shape[-1]))[0]
-                     )(queries, vecs)                    # (B, np*ml)
+        d = kernel_ops.list_scan(queries, list_vecs, probe, ids)
         ids = ids.reshape(B, -1)
-        d = jnp.where(ids < 0, jnp.inf, d)
+        d = jnp.where(ids < 0, jnp.inf, d.reshape(B, -1))  # (B, np*ml)
     with jax.named_scope(SELECT):
         # dedup replicas: mask repeated ids within the top window.  A point
         # sits at most once in each list, so it has at most ``nprobe``

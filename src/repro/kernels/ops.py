@@ -11,6 +11,7 @@ import jax
 
 from repro.kernels import distance as _distance
 from repro.kernels import fused_topk as _fused_topk
+from repro.kernels import list_scan as _list_scan
 from repro.kernels import pq_adc as _pq_adc
 from repro.kernels import ref as ref  # re-export oracles
 
@@ -56,3 +57,9 @@ def adc_lookup(codes, table, *, interpret: bool | None = None, **kw):
 def l2_topk(q, x, k=10, *, interpret: bool | None = None, **kw):
     return _fused_topk.l2_topk(
         q, x, k, interpret=_auto_interpret(interpret), **kw)
+
+
+def list_scan(queries, list_vecs, probe, ids, *,
+              interpret: bool | None = None):
+    return _list_scan.list_scan(
+        queries, list_vecs, probe, ids, interpret=_auto_interpret(interpret))

@@ -49,3 +49,14 @@ def l2_topk_ref(q: jax.Array, x: jax.Array, k: int
     d = l2_distance_ref(q, x)
     neg, idx = jax.lax.top_k(-d, k)
     return -neg, idx
+
+
+def list_scan_ref(queries: jax.Array, list_vecs: jax.Array,
+                  list_ids: jax.Array, probe: jax.Array) -> jax.Array:
+    """Probed-list distances: the gather-then-scan the list-scan kernel
+    replaces.  queries (B, D), list_vecs (L, slots, D), list_ids (L, slots)
+    (-1 pad), probe (B, nprobe) -> (B, nprobe, slots), ``inf`` at padding."""
+    vecs = list_vecs[probe]                          # (B, nprobe, slots, D)
+    d = jax.vmap(lambda q, v: l2_distance_ref(q[None], v.reshape(
+        -1, v.shape[-1]))[0].reshape(v.shape[:2]))(queries, vecs)
+    return jnp.where(list_ids[probe] < 0, jnp.inf, d)

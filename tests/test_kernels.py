@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
-from repro.kernels.ref import adc_lookup_ref, l2_distance_ref, l2_topk_ref
+from repro.kernels import list_scan, ops
+from repro.kernels.ref import (adc_lookup_ref, l2_distance_ref, l2_topk_ref,
+                               list_scan_ref)
 
 
 def _mk(q, n, d, dtype, seed=0):
@@ -124,3 +125,76 @@ def test_l2_topk_block_sweep():
                               block_q=bq, block_n=bn)
         np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
                                    rtol=1e-4, atol=1e-3)
+
+
+# ------------------------------------------------------------ list scan --
+
+def _lists(n_lists, slots, d, seed=0):
+    """Padded posting lists: random lengths, list 1 all padding, list 2
+    with a hole in the middle of its first chunk, list 3 with a hole of
+    whole chunks between real rows; padding rows zero."""
+    rng = np.random.default_rng(seed)
+    vecs = rng.normal(size=(n_lists, slots, d)).astype(np.float32)
+    ids = np.full((n_lists, slots), -1, np.int32)
+    for li in range(n_lists):
+        n = int(rng.integers(1, slots + 1))
+        ids[li, :n] = li * slots + np.arange(n)
+    ids[1] = -1
+    ids[2] = 2 * slots + np.arange(slots)
+    ids[2, 3:11] = -1
+    ids[3] = 3 * slots + np.arange(slots)
+    ids[3, 128:slots - 64] = -1
+    vecs[ids < 0] = 0.0
+    return vecs, ids
+
+
+@pytest.mark.parametrize("b,nprobe,n_lists,slots,d", [
+    (3, 4, 9, 640, 96),      # DEEP width; 5 chunks of 128, 3 steps of 4
+    (2, 4, 6, 384, 200),     # wider than a lane tile, not a multiple of it
+    (2, 3, 5, 300, 96),      # slots not a multiple of 128: padded to 384
+    (1, 4, 6, 512, 96),      # one query; 2 chunks of 256
+    (1, 3, 4, 1152, 960),    # GIST width: one whole list a step
+])
+def test_list_scan_matches_ref(b, nprobe, n_lists, slots, d):
+    vecs, ids = _lists(n_lists, slots, d)
+    rng = np.random.default_rng(1)
+    probe = np.stack([rng.permutation(n_lists)[:nprobe] for _ in range(b)])
+    probe[0, :min(nprobe, 4)] = [1, 2, 3, 0][:nprobe]   # the holed lists
+    probe = probe.astype(np.int32)
+    qs = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    lv, li, pj = jnp.asarray(vecs), jnp.asarray(ids), jnp.asarray(probe)
+    got = np.asarray(ops.list_scan(qs, lv, pj, li[pj], interpret=True))
+    assert got.shape == (b, nprobe, slots)
+    # a chunk with no real row is skipped and reads inf; every other slot
+    # holds its distance
+    chunk, n_chunks = list_scan.chunking(slots, d)
+    held = np.zeros((b, nprobe, n_chunks * chunk), bool)
+    held[..., :slots] = ids[probe] >= 0
+    held = np.repeat(held.reshape(b, nprobe, n_chunks, chunk).any(-1),
+                     chunk, axis=-1)[..., :slots]
+    np.testing.assert_array_equal(np.isinf(got), ~held)
+    want = np.asarray(list_scan_ref(qs, lv, li, pj))
+    got = np.where(ids[probe] < 0, np.inf, got)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("slots,dim", [(616, 96), (1536, 96), (1792, 960),
+                                       (4000, 96), (10_000, 96)])
+def test_list_scan_chunks_tile_the_slots(slots, dim):
+    # equal chunks of whole 128-slot tiles, one bit each in an int32 word
+    chunk, n_chunks = list_scan.chunking(slots, dim)
+    assert chunk % 128 == 0 and n_chunks <= 31
+    assert chunk * n_chunks == -(-slots // 128) * 128
+
+
+@pytest.mark.parametrize("slots", [1024, 1000])
+def test_list_scan_chunk_counts_match_a_count(slots):
+    _, ids = _lists(12, slots, 96, seed=2)
+    probe = np.random.default_rng(3).integers(0, 12, size=(5, 8))
+    chunk, n_chunks = list_scan.chunking(slots, 96)
+    assert (chunk, n_chunks) == (256, 4)
+    fetched = sum(
+        int((ids[lst, c * chunk:(c + 1) * chunk] >= 0).any())
+        for lst in probe.ravel() for c in range(n_chunks))
+    assert list_scan.chunk_counts(ids, probe, 96) == (
+        fetched, probe.size * n_chunks - fetched)

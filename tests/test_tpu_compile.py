@@ -7,6 +7,7 @@ overflow) fails here.  Nothing runs, so nothing is timed or checked for
 results.  The topology is described inside a fixture, never at import: a
 process that loads the TPU library holds it until it exits.
 """
+import functools
 import os
 import re
 
@@ -18,10 +19,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core.cluster_index import SEARCH_STAGES, device_search_batch
 from repro.core.distributed import sharded_search_step
 from repro.core.pq import default_pq_dims
-from repro.kernels import distance, fused_topk, pq_adc
+from repro.kernels import distance, fused_topk, ops, pq_adc
 
 #: one v5e chip's HBM
 HBM_BYTES = 16 * 2**30
+#: the chip benchmark's layouts (lists, slots, D) and their batch sizes
+DEEP_LAYOUT, GIST_LAYOUT = (12_500, 1_536, 96), (1_000, 1_792, 960)
+BULK = [(*DEEP_LAYOUT, 256), (*GIST_LAYOUT, 16)]
+BULK_IDS = ["deep96-f32", "gist960-f32"]
 
 
 @pytest.fixture(scope="module")
@@ -48,6 +53,29 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_search(one_chip):
+    """Compiles ``device_search_batch`` at (lists, slots, D, B), nprobe 32,
+    k 10, once a shape.  Off the TPU the kernels would be compiled for the
+    Pallas interpreter: Mosaic is asked for here."""
+    ops.set_default_interpret(False)
+    done = {}
+
+    def compile_at(L, maxlen, D, B):
+        if (L, maxlen, D, B) not in done:
+            # a fresh callable, so no trace made for the interpreter is reused
+            fn = jax.jit(functools.partial(device_search_batch, nprobe=32,
+                                           k=10))
+            done[L, maxlen, D, B] = fn.lower(
+                _sds((L, D), jnp.float32, one_chip),
+                _sds((L, maxlen, D), jnp.float32, one_chip),
+                _sds((L, maxlen), jnp.int32, one_chip),
+                _sds((B, D), jnp.float32, one_chip)).compile()
+        return done[L, maxlen, D, B]
+    yield compile_at
+    ops.set_default_interpret(None)
 
 
 def _sds(shape, dtype, sharding):
@@ -85,18 +113,33 @@ def test_adc_lookup_compiles(one_chip, m):
     assert _has_kernel(c)
 
 
-def test_device_search_batch_compiles_at_deep_1m(one_chip):
+@pytest.mark.parametrize("maxlen", [640, 616])
+def test_device_search_batch_compiles_at_deep_1m(compile_search, maxlen):
     # the layout chip_smoke.py builds from the DEEP-shaped 1M corpus at 1%
-    # centroids: 15,491 lists, the longest 616 after closure replication
-    L, maxlen, D, B = 15_491, 616, 96, 64
-    fn = jax.jit(device_search_batch, static_argnames=("nprobe", "k"))
-    c = fn.lower(_sds((L, D), jnp.float32, one_chip),
-                 _sds((L, maxlen, D), jnp.float32, one_chip),
-                 _sds((L, maxlen), jnp.int32, one_chip),
-                 _sds((B, D), jnp.float32, one_chip),
-                 nprobe=32, k=10).compile()
+    # centroids: 15,491 lists, the longest 616 after closure replication,
+    # padded to 640 slots; and unpadded, which the list scan pads itself
+    c = compile_search(15_491, maxlen, 96, 64)
+    assert _has_kernel(c)
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("L,maxlen,D,B", BULK, ids=BULK_IDS)
+def test_device_search_batch_scans_lists_in_place(compile_search,
+                                                  L, maxlen, D, B):
+    # the list-scan kernel reads the resident lists: no (B, nprobe, slots,
+    # D) copy and no relayout of the corpus takes device memory
+    c = compile_search(L, maxlen, D, B)
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("B", [1, 2, 4, 8, 16, 32, 64])
+def test_device_search_batch_compiles_online_shapes(compile_search, B):
+    # the online cell's batches, padded to powers of two, on DEEP's layout
+    c = compile_search(*DEEP_LAYOUT, B)
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def _first_scopes(hlo_text: str) -> list:
@@ -109,18 +152,11 @@ def _first_scopes(hlo_text: str) -> list:
     return out
 
 
-@pytest.mark.parametrize("L,maxlen,D,B", [(12_500, 1_536, 96, 256),
-                                          (1_000, 1_792, 960, 16)],
-                         ids=["deep96-f32", "gist960-f32"])
-def test_device_search_batch_names_its_stages(one_chip, L, maxlen, D, B):
+@pytest.mark.parametrize("L,maxlen,D,B", BULK, ids=BULK_IDS)
+def test_device_search_batch_names_its_stages(compile_search, L, maxlen, D, B):
     # the layouts of the chip benchmark's cells, at their batch sizes: every
     # op the TPU compiler keeps metadata for is in one of the four stages
-    fn = jax.jit(device_search_batch, static_argnames=("nprobe", "k"))
-    text = fn.lower(_sds((L, D), jnp.float32, one_chip),
-                    _sds((L, maxlen, D), jnp.float32, one_chip),
-                    _sds((L, maxlen), jnp.int32, one_chip),
-                    _sds((B, D), jnp.float32, one_chip),
-                    nprobe=32, k=10).compile().as_text()
+    text = compile_search(L, maxlen, D, B).as_text()
     scopes = set(_first_scopes(text))
     assert scopes == set(SEARCH_STAGES), sorted(map(str, scopes))
 
