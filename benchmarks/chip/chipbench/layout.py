@@ -21,6 +21,12 @@ in seconds, so that every run can afford a fresh corpus from its seed:
   points for which it is the second nearest, and so on, each group in
   corpus order.
 
+The corpus may be float32 or integer (``chipbench.data.DTYPES``).  The
+centroids are float32, as the program's ``ClusterIndex`` holds them, and
+Lloyd and the closure compute in float32 on one chunk of rows at a time,
+each widened as it is read, so that no widened copy of a whole integer
+corpus is ever held.  The list vectors keep the corpus's type.
+
 The packing is a counting sort on the host: a sort on the TPU takes half
 a minute to compile, at any size.  :func:`build_layout_np` is the same
 algorithm in float64 numpy, the reference the tests compare the device
@@ -53,8 +59,8 @@ def chunk_for(n: int, target: int) -> int:
 
 
 def _ip(x, c):
-    return jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                               precision=BUILD_DOT,
+    return jax.lax.dot_general(x.astype(jnp.float32), c,
+                               (((1,), (1,)), ((), ())), precision=BUILD_DOT,
                                preferred_element_type=jnp.float32)
 
 
@@ -65,23 +71,37 @@ def init_rows(n: int, n_lists: int) -> np.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("n_lists", "iters", "chunk"))
 def lloyd(data, *, n_lists, iters, chunk):
-    """Centroids (n_lists, D) from ``iters`` flat Lloyd iterations."""
+    """Centroids (n_lists, D) float32 from ``iters`` flat Lloyd
+    iterations."""
     n, dim = data.shape
     blocks = data.reshape(n // chunk, chunk, dim)
     init = init_rows(n, n_lists)
+
+    def sums_of(assign):
+        if data.dtype == jnp.float32:
+            return jax.ops.segment_sum(data, assign, n_lists)
+
+        def add(sums, block):
+            x, a = block
+            return sums + jax.ops.segment_sum(x.astype(jnp.float32), a,
+                                              n_lists), None
+        zero = jnp.zeros((n_lists, dim), jnp.float32)
+        return jax.lax.scan(add, zero,
+                            (blocks, assign.reshape(n // chunk, chunk)))[0]
 
     def step(_, cents):
         cn = jnp.sum(cents * cents, axis=1)
         assign = jax.lax.map(
             lambda x: jnp.argmin(cn[None, :] - 2.0 * _ip(x, cents), axis=1),
             blocks).reshape(n)
-        sums = jax.ops.segment_sum(data, assign, n_lists)
+        sums = sums_of(assign)
         counts = jax.ops.segment_sum(jnp.ones((n,), jnp.float32), assign,
                                      n_lists)
         return jnp.where(counts[:, None] > 0,
                          sums / jnp.maximum(counts, 1.0)[:, None], cents)
 
-    return jax.lax.fori_loop(0, iters, step, data[init])
+    return jax.lax.fori_loop(0, iters, step,
+                             data[init].astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("num_replica", "closure_eps",
@@ -96,6 +116,7 @@ def closure(data, cents, *, num_replica, closure_eps, chunk):
     cn = jnp.sum(cents * cents, axis=1)
 
     def one(x):
+        x = x.astype(jnp.float32)
         xn = jnp.sum(x * x, axis=1)
         d = jnp.maximum(xn[:, None] + cn[None, :] - 2.0 * _ip(x, cents), 0.0)
         neg, idx = jax.lax.top_k(-d, r)
@@ -136,7 +157,8 @@ def pack(lists: np.ndarray, n_lists: int, max_len: int
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def fill(data, list_ids, *, block):
-    """List vectors (L, max_len, D): each slot's corpus row, 0 in padding.
+    """List vectors (L, max_len, D) of the corpus's type: each slot's
+    corpus row, 0 in padding.
 
     Filled ``block`` lists at a time: one gather of the whole layout
     would hold a second, relaid-out copy of it in HBM.
@@ -146,7 +168,7 @@ def fill(data, list_ids, *, block):
 
     def body(i, out):
         ids = jax.lax.dynamic_slice_in_dim(oob, i * block, block)
-        vecs = jnp.take(data, ids, axis=0, mode="fill", fill_value=0.0)
+        vecs = jnp.take(data, ids, axis=0, mode="fill", fill_value=0)
         return jax.lax.dynamic_update_slice_in_dim(out, vecs, i * block, 0)
 
     out = jnp.zeros((n_lists, max_len, data.shape[1]), data.dtype)
@@ -169,7 +191,8 @@ def build_layout(data, *, n_lists, iters, num_replica, closure_eps,
 def build_layout_np(data: np.ndarray, *, n_lists: int, iters: int,
                     num_replica: int, closure_eps: float, max_len: int
                     ) -> tuple[np.ndarray, np.ndarray]:
-    """Float64 reference of :func:`build_layout`: (centroids, list_ids)."""
+    """Float64 reference of :func:`build_layout`, for a float or an integer
+    corpus: (centroids, list_ids)."""
     x = data.astype(np.float64)
     cents = x[init_rows(len(x), n_lists)].copy()
     for _ in range(iters):

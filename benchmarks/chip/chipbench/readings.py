@@ -35,3 +35,18 @@ def search_roofline(rec):
     least = max(need["bytes"] / peaks["hbm_Bps"],
                 need["flops"] / peaks["bf16_flops"])
     return 100.0 * least / t["program_s"], "%"
+
+
+def scan_roofline(rec):
+    """Least time the chip could take to read the real rows of the probed
+    lists at its HBM peak, over the scan stage's device time, both per
+    batch, in per cent."""
+    t, need, peaks = rec.get("trace"), rec.get("search_bytes"), rec.get("peaks")
+    found = t and t.get("stages")
+    if not found or not need or not peaks or not rec.get("batches"):
+        return None
+    scan_s = found["seconds"]["scan"] / found["executions"]
+    if scan_s <= 0:
+        return None
+    least = need["row_bytes"] / len(rec["batches"]) / peaks["hbm_Bps"]
+    return 100.0 * least / scan_s, "%"

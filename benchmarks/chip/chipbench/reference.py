@@ -24,6 +24,12 @@ cluster search:
   ``PROBE_TIE`` of the norms below the boundary, so that a near tie at
   the boundary cannot be read as a fault.
 
+Rows may be float32 or integer (``chipbench.data.DTYPES``).  Every
+function widens integer rows before any arithmetic: to float64 on the
+host, to float32 on the device, where a squared distance of 8-bit rows
+below 2**24 (128-d uint8, 100-d int8) is an exact integer too.  So the
+checks read exact integer distances on integer data.
+
 ``recall`` (exact top-k over the whole corpus, on a smaller sample, by
 :func:`exact_topk` on the device) is reported beside them but not
 compared: it is a property of the index.
@@ -60,6 +66,12 @@ SCREEN_BYTES = 3 << 30
 HOST_CHECKS = 2000
 
 
+def wide(a: np.ndarray) -> np.ndarray:
+    """Host rows as the arithmetic reads them: float32 as they are, integer
+    rows in float64, where every sum of their squares is exact."""
+    return a if a.dtype == np.float32 else a.astype(np.float64)
+
+
 def _sq64(q: np.ndarray, x: np.ndarray) -> np.ndarray:
     """Float64 squared distances of rows ``x`` (m, D) to ``q`` (D,)."""
     diff = x.astype(np.float64) - q.astype(np.float64)
@@ -82,9 +94,10 @@ def served_sq(data: np.ndarray, pool: np.ndarray, qidx: np.ndarray,
     every served row to its query, and |q|^2 + |x|^2; ``inf`` where an id
     names no corpus row.
 
-    The distance is the float32 sum of squared differences, whose
-    rounding is relative to the distance itself, far below the rounding
-    of the L2 expansion relative to the norms that the checks measure.
+    On float32 rows the distance is the float32 sum of squared
+    differences, whose rounding is relative to the distance itself, far
+    below the rounding of the L2 expansion relative to the norms that the
+    checks measure; on integer rows it is exact.
     """
     n = len(data)
     dist = np.full(ids.shape, np.inf, np.float32)
@@ -92,8 +105,8 @@ def served_sq(data: np.ndarray, pool: np.ndarray, qidx: np.ndarray,
     for s in range(0, len(qidx), chunk):
         row = ids[s:s + chunk]
         ok = (row >= 0) & (row < n)
-        q = pool[qidx[s:s + chunk]]
-        x = data[np.where(ok, row, 0)]
+        q = wide(pool[qidx[s:s + chunk]])
+        x = wide(data[np.where(ok, row, 0)])
         diff = x - q[:, None, :]
         d = np.einsum("mkd,mkd->mk", diff, diff)
         sc = np.einsum("md,md->m", q, q)[:, None] \
@@ -141,7 +154,7 @@ def host_search(q: np.ndarray, data: np.ndarray, lists: np.ndarray,
     """Float64 distances of the k nearest rows of ``lists`` to ``q``."""
     cand = list_ids[lists].ravel()
     cand = np.unique(cand[cand >= 0])
-    diff = data[cand] - q.astype(np.float32)
+    diff = wide(data[cand]) - wide(q)
     d32 = np.einsum("nd,nd->n", diff, diff)
     top = cand[np.argpartition(d32, min(k + REFINE, len(cand) - 1))
                [:k + REFINE]]
@@ -175,12 +188,13 @@ def _screen(centroids, list_vecs, list_ids, queries, served, kth, tol, *,
             nprobe):
     """(B,) bool: some row of the query's ``nprobe`` nearest lists (float32
     at HIGHEST) that is not among ``served`` lies nearer than the served
-    k-th best ``kth`` by more than ``tol``."""
+    k-th best ``kth`` by more than ``tol``.  ``queries`` are float32; the
+    list rows are widened to it as they are gathered."""
     qc = jax.lax.dot_general(queries, centroids, (((1,), (1,)), ((), ())),
                              precision=jax.lax.Precision.HIGHEST)
     cd = jnp.sum(centroids * centroids, 1)[None, :] - 2.0 * qc
     _, probe = jax.lax.top_k(-cd, nprobe)
-    diff = list_vecs[probe] - queries[:, None, None, :]
+    diff = list_vecs[probe].astype(jnp.float32) - queries[:, None, None, :]
     d = jnp.sum(diff * diff, axis=-1)                   # (B, nprobe, slots)
     ids = list_ids[probe]
     new = ~jnp.any(ids[..., None] == served[:, None, None, :], axis=-1)
@@ -237,6 +251,8 @@ def recall(ids: np.ndarray, exact: np.ndarray) -> float:
 def exact_topk(corpus, queries, *, k):
     """Ids of the exact k nearest corpus rows (ranked in float32 at
     HIGHEST precision; a near tie may swap, which recall hardly sees)."""
+    corpus = corpus.astype(jnp.float32)
+    queries = queries.astype(jnp.float32)
     ip = jax.lax.dot_general(queries, corpus, (((1,), (1,)), ((), ())),
                              precision=jax.lax.Precision.HIGHEST)
     d = jnp.sum(corpus * corpus, axis=1)[None, :] - 2.0 * ip
@@ -255,8 +271,10 @@ def to_bf16(x):
 
 
 def _sq_l2_bf16(q, x):
-    """Squared L2 of bfloat16-rounded rows, in float32 arithmetic."""
-    q, x = to_bf16(q), to_bf16(x)
+    """Squared L2 of bfloat16-rounded rows, in float32 arithmetic (integer
+    rows widened first)."""
+    q = to_bf16(q.astype(jnp.float32))
+    x = to_bf16(x.astype(jnp.float32))
     qn = jnp.sum(q * q, axis=-1)[:, None]
     xn = jnp.sum(x * x, axis=-1)[None, :]
     ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
@@ -267,7 +285,8 @@ def _sq_l2_bf16(q, x):
 @functools.partial(jax.jit, static_argnames=("nprobe", "k"))
 def control_search(centroids, list_vecs, list_ids, queries, *, nprobe, k):
     """The cluster search at the precision below the configuration's
-    float32: bfloat16 distance dots."""
+    float32: bfloat16 distance dots.  On rows of 8-bit integers, which
+    bfloat16 holds exactly, it is exact."""
     b = queries.shape[0]
     _, probe = jax.lax.top_k(-_sq_l2_bf16(queries, centroids), nprobe)
     vecs = list_vecs[probe].reshape(b, -1, list_vecs.shape[-1])

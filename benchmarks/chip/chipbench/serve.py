@@ -27,7 +27,7 @@ class Server:
 
     device: jax.Device
     search: Callable            # device queries (b, D) -> (ids, dists)
-    pool: np.ndarray            # (P, D) float32 queries, on the host
+    pool: np.ndarray            # (P, D) queries of the corpus's type, on the host
     dim: int
 
 

@@ -26,8 +26,9 @@ one shape's program and another in the next is so never charged with the
 wrong meaning.
 
 Also here: the host's own events during the result copy of the slowest
-batch (:func:`worst_copy`), for the record.  ``trace_stages.py`` reads
-both over a traced window of a cell; a benchmark run does not yet.
+batch (:func:`worst_copy`), for the record.  A ``--trace 1`` run of
+``run.py`` reads both after its window (``run.read_trace``), and so does
+``trace_stages.py``.
 """
 from __future__ import annotations
 

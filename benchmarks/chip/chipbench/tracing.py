@@ -151,10 +151,12 @@ def search_bytes(queries: np.ndarray, centroids: np.ndarray,
     """HBM bytes and FLOPs a cluster search needs for ``queries``.
 
     Per query: the real (unpadded) lengths of the ``nprobe`` lists nearest
-    to it, each row D float32 plus a 4-byte id, and the query itself; per
-    batch, the centroids once.  Padding and gather copies are not needed,
-    so they are not counted.  FLOPs: 2·D per centroid and per candidate.
-    The probe is the exact float32 top-``nprobe`` (HIGHEST precision).
+    to it, each row D elements of the queries' type plus a 4-byte id, and
+    the query itself; per batch, the float32 centroids once.  Padding and
+    gather copies are not needed, so they are not counted.  ``row_bytes``
+    is the list rows' elements alone, what a scan of the lists has to
+    read.  FLOPs: 2·D per centroid and per candidate.  The probe is the
+    exact float32 top-``nprobe`` (HIGHEST precision).
     """
     import jax
     import jax.numpy as jnp
@@ -178,6 +180,10 @@ def search_bytes(queries: np.ndarray, centroids: np.ndarray,
         q[:len(part)] = part
         rows += int(np.asarray(probed_rows(q, c, lens))[:len(part)].sum())
     nq = len(queries)
-    hbm = rows * (dim * 4 + 4) + nq * dim * 4 + n_batches * n_lists * dim * 4
+    item = queries.dtype.itemsize
+    row_bytes = rows * dim * item
+    hbm = row_bytes + rows * 4 + nq * dim * item \
+        + n_batches * n_lists * dim * 4
     flops = 2 * dim * (rows + nq * n_lists)
-    return dict(bytes=hbm, flops=flops, rows=rows, queries=nq)
+    return dict(bytes=hbm, flops=flops, rows=rows, queries=nq,
+                row_bytes=row_bytes)

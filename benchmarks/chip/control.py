@@ -63,10 +63,10 @@ def main(argv=None) -> int:
         res = R.run_cell(config, traffic, seed=seed, seconds=args.seconds,
                          trace=False, device=device, metric_names=[],
                          t_start=t, search_impl=impl, log=log)
-        info.pop("layout", None)
         print(json.dumps(dict(
             workload=args.workload, impl=args.impl, seed=seed,
             correct=res["correct"], run_s=time.perf_counter() - t,
+            memory_peak_bytes=res["device"]["memory_peak_bytes"],
             checks={k: v["value"] for k, v in res["checks"].items()},
             info=info)), flush=True)
     return 0

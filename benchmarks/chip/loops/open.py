@@ -72,7 +72,7 @@ def run(server, config: dict, traffic: dict, seconds: float, seed: int
         with jax.profiler.TraceAnnotation(BATCH):
             with jax.profiler.TraceAnnotation(ASSEMBLE):
                 b = next(s for s in sizes if s >= j - i)
-                q = np.zeros((b, server.dim), np.float32)
+                q = np.zeros((b, server.dim), pool.dtype)
                 idx = np.arange(i, j) % len(pool)
                 q[:j - i] = pool[idx]
             out_ids, out_d = serve_batch(server, q)

@@ -15,11 +15,22 @@ builds the padded posting-list layout on the device (``chipbench.layout``),
 compiles and warms every batch shape the loop will send (set-up ends
 here), then drives the loop for ``--seconds``.  With ``--trace 1`` the
 window runs under the profiler and the per-layer metrics are read from
-the trace; otherwise the end-to-end ones are reported.  After the window
-the device memory peak is read, the program's state is freed, and every
-answer is checked against references that import nothing of the program
-(``chipbench.reference``).  The last line of stdout is one JSON object;
-the compared numbers, each beside its limit, end both it and stderr.
+the trace, the search's stage times among them (``chipbench.stages``);
+otherwise the end-to-end ones are reported.
+
+The element type is the configuration's ``dtype``: ``float32``, ``int8``
+or ``uint8`` (``chipbench.data.DTYPES``); any other, or none, ends the
+run with status 2 and no result.  The search under test is called as
+``search(centroids, list_vecs, list_ids, queries, nprobe=, k=)`` with
+``queries`` (b, D) and ``list_vecs`` (L, slots, D) in that type,
+``centroids`` (L, D) float32 and ``list_ids`` (L, slots) int32, -1 in
+padding; it returns (ids (b, k) int32, squared distances (b, k) float32).
+
+After the window the device memory peak is read, the program's state is
+freed, and every answer is checked against references that import
+nothing of the program (``chipbench.reference``).  The last line of
+stdout is one JSON object; the compared numbers, each beside its limit,
+end both it and stderr.
 
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 with status 2 and prints no result.
@@ -195,7 +206,53 @@ def make_corpus(config: dict, key):
     from chipbench import data
     return data.make_corpus(
         key, n=int(config["n"]), n_pool=int(config["pool"]),
-        dim=int(config["dim"]), intrinsic_dim=int(config["intrinsic_dim"]))
+        dim=int(config["dim"]), intrinsic_dim=int(config["intrinsic_dim"]),
+        dtype=data.element_type(config))
+
+
+def measure(server, loop, config: dict, traffic: dict, seconds: float,
+            seed: int, trace_dir: str | None = None) -> dict:
+    """The measured window: the loop's record.  With ``trace_dir``, under
+    the profiler, which writes its trace there."""
+    import jax
+    if trace_dir:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        return loop.run(server, config, traffic, seconds, seed)
+    finally:
+        if trace_dir:
+            jax.profiler.stop_trace()
+        gc.unfreeze()
+
+
+def read_trace(rec: dict, trace_dir: str, *, search_impl, lay: dict,
+               pool, shapes: list[int], config: dict, device) -> None:
+    """Reduce a traced window into ``rec``, then remove ``trace_dir``:
+    ``trace`` (device and host times, with the search's ``stages``
+    charged op by op from the programs of every warm shape),
+    ``search_bytes`` and the device's ``peaks``."""
+    import jax
+    import numpy as np
+
+    from chipbench import stages, tracing
+    nprobe, k = int(config["nprobe"]), int(config["k"])
+    try:
+        rec["trace"] = tracing.reduce_dir(trace_dir, program=SEARCH_PROGRAM)
+        stages.add_to_trace(
+            rec, trace_dir, SEARCH_PROGRAM, search_impl,
+            (lay["centroids"], lay["list_vecs"], lay["list_ids"]),
+            [jax.device_put(pool[:b], device) for b in shapes],
+            nprobe=nprobe, k=k)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    lens = np.asarray(jax.numpy.sum(lay["list_ids"] >= 0, axis=1))
+    rec["search_bytes"] = tracing.search_bytes(
+        pool[rec["qidx"]], np.asarray(lay["centroids"]), lens,
+        n_batches=len(rec["batches"]), nprobe=nprobe)
+    rec["peaks"] = tracing.peaks_of(device)
 
 
 def run_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
@@ -206,7 +263,7 @@ def run_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
     import jax
     import numpy as np
 
-    from chipbench import data, reference, tracing
+    from chipbench import data, readings, reference, stages
     from chipbench.stats import percentile
 
     log = log or (lambda *a: print(*a, file=sys.stderr, flush=True))
@@ -222,17 +279,7 @@ def run_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
     # ---- the measured window
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     before = compiles.count
-    if trace:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    try:
-        rec = loop.run(server, config, traffic, seconds, seed)
-    finally:
-        if trace:
-            jax.profiler.stop_trace()
-        gc.unfreeze()
+    rec = measure(server, loop, config, traffic, seconds, seed, trace_dir)
     in_window = compiles.count - before
     stats = device.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
@@ -243,13 +290,9 @@ def run_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
         f"compiles in window {in_window}, peak {peak} B")
 
     if trace:
-        rec["trace"] = tracing.reduce_dir(trace_dir, program=SEARCH_PROGRAM)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        lens = np.asarray(jax.numpy.sum(lay["list_ids"] >= 0, axis=1))
-        rec["search_bytes"] = tracing.search_bytes(
-            pool_h[rec["qidx"]], np.asarray(lay["centroids"]), lens,
-            n_batches=len(rec["batches"]), nprobe=nprobe)
-        rec["peaks"] = tracing.peaks_of(device)
+        read_trace(rec, trace_dir, search_impl=search_impl, lay=lay,
+                   pool=pool_h, shapes=loop.warm_shapes(config, traffic),
+                   config=config, device=device)
 
     # ---- free the program's state, then the references
     t_ref = time.perf_counter()
@@ -286,13 +329,25 @@ def run_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
     limits = dict(unanswered=0, bad_answers=0, **config["limits"])
     checks = {name: (numbers.pop(name), lim) for name, lim in limits.items()}
     info = dict(recall=reference.recall(rec["ids"][few], exact),
-                reference_s=time.perf_counter() - t_ref,
+                setup_s=setup_s, reference_s=time.perf_counter() - t_ref,
                 pool_wrapped=rec["wrapped"], compiles_in_window=in_window,
                 layout=build, **numbers)
     for key_ in ("offered_qps", "achieved_qps", "backlog_at_close",
                  "worst_latency_s", "worst_due_s"):
         if key_ in rec:
             info[key_] = rec[key_]
+    if rec.get("trace"):
+        # what charging the stages cost after the window, the search's
+        # device time that the stages split, and the part of it that no
+        # stage explains
+        info["stages_cost_s"] = rec["trace"]["stages_cost_s"]
+        searched = readings.search_device_ms(rec)
+        info["search_device_ms"] = searched and searched[0]
+        found = rec["trace"]["stages"]
+        if found:
+            info["other_device_ms"] = \
+                1e3 * found["seconds"][stages.OTHER] / found["executions"]
+            info["unexplained_programs"] = len(found["unexplained"])
     if len(rec.get("latencies_s", ())):
         # the tail, printed for the record: a ~0.12 s stall of the shared
         # host lands in about half of all windows and moves it tenfold
@@ -334,6 +389,12 @@ def main(argv: list[str] | None = None) -> int:
     cell, config, traffic, e2e, layer = cell_spec(bench, args.workload)
     use_cache()
     sys.path[:0] = [HERE, os.path.join(ROOT, "src")]
+    from chipbench import data
+    try:
+        data.element_type(config)
+    except ValueError as e:
+        print(f"run.py: {e}; no result", file=sys.stderr)
+        return 2
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
     try:

@@ -32,14 +32,19 @@ def test_union_of_intervals():
     assert stats.merged([]) == []
 
 
-def test_search_bytes_counts_real_list_lengths_not_padding():
+@pytest.mark.parametrize("dtype", ["float32", "int8", "uint8"])
+def test_search_bytes_counts_real_list_lengths_not_padding(dtype):
     # 4 lists on the axes; query near list 0 probes lists 0 and (by tie
     # break of the nearer) one more.  Lengths 3, 5, 7, 11; padding unseen.
+    # Rows and queries count at their type's size, centroids at 4 bytes.
     cents = np.eye(4, dtype=np.float32) * 10
     lens = np.array([3, 5, 7, 11])
-    q = np.array([[10, 1, 0, 0], [0, 0, 10, 0.5]], np.float32)
+    q = np.array([[10, 1, 0, 0], [0, 0, 10, 1]]).astype(dtype)
     got = tracing.search_bytes(q, cents, lens, n_batches=1, nprobe=2)
+    item = np.dtype(dtype).itemsize
     rows = (3 + 5) + (7 + 11)
     assert got["rows"] == rows
-    assert got["bytes"] == rows * (4 * 4 + 4) + 2 * 4 * 4 + 1 * 4 * 4 * 4
+    assert got["row_bytes"] == rows * 4 * item
+    assert got["bytes"] == rows * (4 * item + 4) + 2 * 4 * item \
+        + 1 * 4 * 4 * 4
     assert got["flops"] == 2 * 4 * (rows + 2 * 4)

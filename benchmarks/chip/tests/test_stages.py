@@ -259,3 +259,22 @@ def test_trace_stages_reads_no_stage_off_the_tpu():
     assert all(out[f"{s}_device_ms"] is None for s in stages.STAGES)
     assert out["worst_copy"]["copy_ms"] > 0
     assert out["stages_cost_s"] > 0
+
+
+def test_traced_run_leaves_out_what_it_cannot_read_off_the_tpu():
+    """A traced run of the bulk cell on the CPU: the stage metrics and the
+    scan roofline find no device plane and are left out, not read as 0."""
+    bench = R.load_json(os.path.join(R.ROOT, "BENCHMARK.json"))
+    _, config, traffic, _, layer = R.cell_spec(bench, "deep96-f32.bulk")
+    assert {f"{s}_device_ms.bulk" for s in stages.STAGES} \
+        | {"scan_roofline.bulk"} <= set(layer)
+    config = dict(config, n=6000, pool=2048, build_chunk=2000, batch=16,
+                  recall_sample=10)
+    res = R.run_cell(config, traffic, seed=2 ** 33 + 7, seconds=0.3,
+                     trace=True, device=jax.devices()[0], metric_names=layer,
+                     t_start=0.0, search_impl=R.program_search(),
+                     log=lambda *a: None)
+    assert res["correct"], res["checks"]
+    assert res["metrics"]
+    assert not any(m.endswith("_device_ms.bulk") or m == "scan_roofline.bulk"
+                   for m in res["metrics"])

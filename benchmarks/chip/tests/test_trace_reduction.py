@@ -68,6 +68,17 @@ def test_roofline_reading_is_least_time_over_program_time():
     assert readings.search_roofline({"trace": None}) is None
 
 
+def test_scan_roofline_reads_the_scan_stage_only():
+    rec = dict(batches=[None] * 4, peaks=dict(hbm_Bps=1e9, bf16_flops=1e12),
+               search_bytes=dict(row_bytes=4e6),
+               trace=dict(stages=dict(executions=4, seconds=dict(
+                   probe=1.0, gather=1.0, scan=0.008, select=1.0, other=0.0))))
+    assert readings.scan_roofline(rec) == (pytest.approx(50.0), "%")
+    rec["trace"]["stages"] = None
+    assert readings.scan_roofline(rec) is None
+    assert readings.scan_roofline(dict(rec, trace=None)) is None
+
+
 def test_no_batch_no_reading():
     assert tracing.reduce([], [], [], program="search_step") is None
     assert readings.idle_share({"trace": None}) is None

@@ -5,22 +5,20 @@ a cell, and the host's own events in the slowest batch's result copy:
     python benchmarks/chip/trace_stages.py --workload deep96-f32.bulk \\
         --seed 7 --seconds 10
 
-One set-up as a benchmark run makes it, then one window of the cell's
-loop under the profiler, with the options of ``run.py --trace 1``.  The
-trace is reduced by ``chipbench.tracing`` and its search ops are charged
-stage by stage by ``chipbench.stages``.  Prints one JSON line: the ms a
-batch of each stage and of ``other``, the search program's device ms a
-batch, the seconds the stage map and its reduction took after the window,
-and the slowest batch's result copy.  Answers are not checked.  The
-benchmark's runs never run this: the stages are not among its metrics.
+One set-up and one traced window, by the same code as ``run.py --trace
+1`` (``run.set_up``, ``run.measure``, ``run.read_trace``), whose search
+ops ``chipbench.stages`` charges stage by stage.  Prints one JSON line:
+the ms a batch of each stage and of ``other``, the search program's
+device ms a batch, the seconds the stage map and its reduction took after
+the window, and the slowest batch's result copy.  Answers are not
+checked: a benchmark run reports the stages, checked, as its
+``<stage>_device_ms`` metrics; this shows the whole reduction.
 """
 from __future__ import annotations
 
 import argparse
-import gc
 import json
 import os
-import shutil
 import sys
 import tempfile
 
@@ -30,34 +28,17 @@ import run as R
 def trace_cell(config: dict, traffic: dict, *, seed: int, seconds: float,
                device) -> dict:
     """One set-up and one traced window of the cell on ``device``."""
-    import jax
-
-    from chipbench import readings, stages, tracing
+    from chipbench import readings, stages
 
     search = R.program_search()
     server, lay, build, loop = R.set_up(config, traffic, seed=seed,
                                         device=device, search_impl=search)
     R.settle_heap()
     trace_dir = tempfile.mkdtemp(prefix="chipbench-stages-")
-    try:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        try:
-            rec = loop.run(server, config, traffic, seconds, seed)
-        finally:
-            jax.profiler.stop_trace()
-            gc.unfreeze()
-        rec["trace"] = tracing.reduce_dir(trace_dir, program=R.SEARCH_PROGRAM)
-        stages.add_to_trace(
-            rec, trace_dir, R.SEARCH_PROGRAM, search,
-            (lay["centroids"], lay["list_vecs"], lay["list_ids"]),
-            [jax.device_put(server.pool[:b], device)
-             for b in loop.warm_shapes(config, traffic)],
-            nprobe=int(config["nprobe"]), k=int(config["k"]))
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    rec = R.measure(server, loop, config, traffic, seconds, seed, trace_dir)
+    R.read_trace(rec, trace_dir, search_impl=search, lay=lay,
+                 pool=server.pool, shapes=loop.warm_shapes(config, traffic),
+                 config=config, device=device)
     t = rec["trace"] or {}
     found = t.get("stages")
     out = dict(batches=len(rec["batches"]), layout=build)
