@@ -30,6 +30,7 @@ from repro.core import kmeans as km
 from repro.core.distances import np_sq_l2, pairwise_sq_l2, topk_smallest
 from repro.core.types import (ClusterIndexParams, FetchBatch, FetchRequest,
                               QueryMetrics, SearchParams, SearchResult)
+from repro.kernels import list_scan
 from repro.kernels import ops as kernel_ops
 from repro.storage.object_store import ObjectStore
 
@@ -242,22 +243,25 @@ class ClusterIndex:
     def device_arrays(self, max_len: int | None = None) -> dict[str, np.ndarray]:
         """Padded resident layout for the TPU serving path.
 
-        Returns centroids (L, D), list_vecs (L, maxlen, D),
-        list_ids (L, maxlen) int32 (-1 pad), list_len (L,) int32.
-        ``maxlen`` defaults to the longest list rounded up to a multiple of
-        128, so that TPU lays the slots on the lanes and the list scan
-        reads each list where it lies.
+        Returns centroids (L, D) float32, list_vecs (L, maxlen, W) of the
+        corpus's type, list_ids (L, maxlen) int32 (-1 pad), list_len (L,)
+        int32.  ``maxlen`` defaults to the longest list rounded up to a
+        multiple of 128, so that the list scan reads each list where it
+        lies; ``W`` is D, or for 8-bit rows D rounded up to a multiple of
+        128 with zeros (``repro.kernels.list_scan.row_width``).
         """
         L = self.meta.n_lists
         dim = self.meta.dim
+        dtype = np.dtype(self.meta.dtype)
         ml = int(max_len or -(-self.meta.list_lengths.max() // 128) * 128)
-        vecs = np.zeros((L, ml, dim), dtype=np.float32)
+        width = list_scan.row_width(dim, dtype.itemsize)
+        vecs = np.zeros((L, ml, width), dtype=dtype)
         ids = np.full((L, ml), -1, dtype=np.int32)
         for li in range(L):
             pids, pv = self.store.get(("list", li))
             cnt = min(len(pids), ml)
             if cnt:
-                vecs[li, :cnt] = pv[:cnt].astype(np.float32)
+                vecs[li, :cnt, :dim] = pv[:cnt]
                 ids[li, :cnt] = pids[:cnt]
         return dict(
             centroids=self.meta.tree.centroids.astype(np.float32),
@@ -266,13 +270,19 @@ class ClusterIndex:
 
 
 def device_search_batch(
-    centroids: jax.Array,     # (L, D)
-    list_vecs: jax.Array,     # (L, maxlen, D)
+    centroids: jax.Array,     # (L, D) float32
+    list_vecs: jax.Array,     # (L, maxlen, W)
     list_ids: jax.Array,      # (L, maxlen) int32, -1 padded
     queries: jax.Array,       # (B, D)
     *, nprobe: int, k: int,
 ) -> tuple[jax.Array, jax.Array]:
     """Resident-array batched cluster search (pjit/TPU path).
+
+    ``queries`` and ``list_vecs`` are float32, int8 or uint8, of one type;
+    an 8-bit corpus is held and read as bytes, and its distances are
+    exact integers.  ``W`` is D, or for 8-bit rows D rounded up to a
+    multiple of 128 with zeros (:meth:`ClusterIndex.device_arrays`).
+    Returns ids (B, k) int32 and squared distances (B, k) float32.
 
     One pipeline: centroid matmul -> top-nprobe -> the probed lists' ids ->
     the list-scan kernel's distances, masked at padding -> global top-k.

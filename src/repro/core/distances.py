@@ -39,10 +39,12 @@ def _as_f32(x: Array) -> Array:
 def pairwise_sq_l2(q: Array, x: Array) -> Array:
     """Squared L2 distances.  q: (Q, D), x: (N, D) -> (Q, N) float32.
 
-    Supports float32/bfloat16/int8 inputs; accumulation is always f32
-    (int8 inputs go through the int32 dot-product path first).
+    Supports float32/bfloat16/int8/uint8 inputs; accumulation is always
+    f32.  Two int8 operands go through the int32 dot-product path first;
+    any other pair is widened to f32 (exact for 8-bit integers), such as
+    integer queries against the f32 centroids of the device search.
     """
-    if q.dtype == jnp.int8 or x.dtype == jnp.int8:
+    if q.dtype == jnp.int8 and x.dtype == jnp.int8:
         qi = q.astype(jnp.int32)
         xi = x.astype(jnp.int32)
         qn = jnp.sum(qi * qi, axis=-1, dtype=jnp.int32)[:, None]

@@ -10,11 +10,22 @@ probed list once, from where it lies, and writes only the distances:
 
 in float32 on the VPU (no MXU pass rounds an operand to bf16).
 
-Layout.  TPU stores an (L, slots, D) array with the slots on the lanes
-when that pads less than D on the lanes would (D = 96 or 960 against a
-multiple of 128 slots), so the kernel reads the (L, D, slots) view of the
-same bytes: a list is a (D, slots) matrix, a chunk of slots is a strided
-DMA, and the sum over D runs down the sublanes into a lane-dense row.
+Element types.  The rows are float32, int8 or uint8, and are fetched in
+their own type: an 8-bit row moves one byte an element.  A fetched chunk
+is widened in VMEM, integers through int32 (Mosaic casts no uint8 to
+float32), and the queries before the kernel.  On 8-bit rows every
+difference, square and partial sum is an integer below 2**24 (at most
+D · 255² = 8,323,200 at D = 128), so float32 holds each distance
+exactly, whatever the order of the sum.
+
+Layout.  TPU lays an (L, slots, D) array out to pad least.  Where D is a
+multiple of 128 (BIGANN's 128-d rows) the rows stay on the lanes, and the
+kernel reads (chunk, D) row blocks, contiguous in HBM, and turns each
+widened block into (D, chunk) in VMEM.  Otherwise (DEEP's 96, GIST's 960)
+TPU puts the slots on the lanes, so the kernel reads the (L, D, slots)
+view of the same bytes: a list is a (D, slots) matrix and a chunk of slots
+is a strided DMA.  Either way the sum over D runs down the sublanes into a
+lane-dense row.
 
 Chunks.  The slots are cut into equal chunks of ``chunk`` slots; a bit per
 (query, probe, chunk) says the chunk holds a real row.  Chunks without one
@@ -48,8 +59,9 @@ _MAX_CHUNKS = 31
 _MAX_GROUP = 8
 
 
-def chunking(slots: int, dim: int) -> tuple[int, int]:
-    """(chunk, n_chunks) for lists of ``slots`` rows of ``dim`` floats.
+def chunking(slots: int, dim: int, itemsize: int) -> tuple[int, int]:
+    """(chunk, n_chunks) for lists of ``slots`` rows of ``dim`` elements of
+    ``itemsize`` bytes.
 
     The chunks tile the slots rounded up to 128 (a DMA cuts the lanes of a
     tiled array only at multiples of 128) in equal parts of a multiple of
@@ -57,11 +69,20 @@ def chunking(slots: int, dim: int) -> tuple[int, int]:
     DMA stays within ``_CHUNK_BYTES``, and at most ``_MAX_CHUNKS`` parts.
     """
     tiles = -(-slots // _LANE)
-    want = _CHUNK_BYTES // (4 * dim * _LANE)
+    want = _CHUNK_BYTES // (itemsize * dim * _LANE)
     fewest = -(-tiles // _MAX_CHUNKS)
     parts = [t for t in range(fewest, tiles + 1) if tiles % t == 0]
     per = max([t for t in parts if t <= want], default=parts[0])
     return per * _LANE, tiles // per
+
+
+def row_width(dim: int, itemsize: int) -> int:
+    """Elements a resident row of ``dim`` elements of ``itemsize`` bytes
+    takes, zeros after the first ``dim``: 8-bit rows fill whole lane tiles,
+    else TPU keeps them in a tiling no DMA of one list can cut (an int8
+    (L, slots, 100) array is held as 100 planes of (L, slots)); float32
+    rows are kept as they are."""
+    return -(-dim // _LANE) * _LANE if itemsize == 1 else dim
 
 
 def _group(nprobe: int, list_bytes: int) -> int:
@@ -84,18 +105,27 @@ def chunk_bits(ids: jax.Array, chunk: int, n_chunks: int) -> jax.Array:
     return jnp.sum(held.astype(jnp.int32) * weights, axis=-1)
 
 
-def chunk_counts(list_ids: np.ndarray, probe: np.ndarray,
-                 dim: int) -> tuple[int, int]:
+def chunk_counts(list_ids: np.ndarray, probe: np.ndarray, dim: int,
+                 itemsize: int) -> tuple[int, int]:
     """Chunks the kernel fetches and skips for ``probe`` (B, nprobe) over
-    ``list_ids`` (L, slots), lists of ``dim`` floats a row."""
-    chunk, n_chunks = chunking(list_ids.shape[1], dim)
+    ``list_ids`` (L, slots), lists of ``dim`` elements of ``itemsize``
+    bytes a row."""
+    chunk, n_chunks = chunking(list_ids.shape[1], dim, itemsize)
     bits = np.asarray(chunk_bits(jnp.asarray(list_ids), chunk, n_chunks))
     held = (bits[np.asarray(probe)][..., None] >> np.arange(n_chunks)) & 1
     return int(held.sum()), int(held.size - held.sum())
 
 
+def _widen(x: jax.Array) -> jax.Array:
+    """Rows as float32, exactly: integers through int32, which Mosaic
+    widens from int8 and uint8 alike."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _scan_kernel(probe_ref, bits_ref, q_ref, x_hbm, out_ref, buf, sem, *,
-                 group: int, chunk: int, n_chunks: int):
+                 group: int, chunk: int, n_chunks: int, rows: bool):
     step = pl.program_id(0)
     slot = step % 2
 
@@ -111,9 +141,14 @@ def _scan_kernel(probe_ref, bits_ref, q_ref, x_hbm, out_ref, buf, sem, *,
         jax.lax.fori_loop(0, group, one, 0)
 
     def copy(at, into, g, lo):
-        return pltpu.make_async_copy(
-            x_hbm.at[probe_ref[at * group + g], :, pl.ds(lo, chunk)],
-            buf.at[into, g, :, pl.ds(lo, chunk)], sem.at[into])
+        # rows on the lanes: the chunk is (chunk, D) rows; else (D, chunk)
+        if rows:
+            src = x_hbm.at[probe_ref[at * group + g], pl.ds(lo, chunk), :]
+            dst = buf.at[into, g, pl.ds(lo, chunk), :]
+        else:
+            src = x_hbm.at[probe_ref[at * group + g], :, pl.ds(lo, chunk)]
+            dst = buf.at[into, g, :, pl.ds(lo, chunk)]
+        return pltpu.make_async_copy(src, dst, sem.at[into])
 
     def start(at, into):
         each_chunk(at, lambda g, lo, real:
@@ -135,16 +170,20 @@ def _scan_kernel(probe_ref, bits_ref, q_ref, x_hbm, out_ref, buf, sem, *,
     # A row's store is a select over the block's rows (at most 8, one tile
     # of sublanes): Mosaic stores no single row at a dynamic sublane.
     out_ref[...] = jnp.full(out_ref.shape, jnp.inf, jnp.float32)
-    q = q_ref[...].astype(jnp.float32)                   # (D, 1)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (group, chunk), 0)
+    q = q_ref[...]                                       # (D, 1) f32
+    group_rows = jax.lax.broadcasted_iota(jnp.int32, (group, chunk), 0)
 
     def scan(g, lo, real):
         @pl.when(real)
         def _():
-            d = buf[slot, g, :, pl.ds(lo, chunk)].astype(jnp.float32) - q
+            if rows:
+                x = _widen(buf[slot, g, pl.ds(lo, chunk), :]).T
+            else:
+                x = _widen(buf[slot, g, :, pl.ds(lo, chunk)])
+            d = x - q                                        # (D, chunk)
             dist = jnp.sum(d * d, axis=0, keepdims=True)     # (1, chunk)
             out_ref[:, pl.ds(lo, chunk)] = jnp.where(
-                rows == g, dist, out_ref[:, pl.ds(lo, chunk)])
+                group_rows == g, dist, out_ref[:, pl.ds(lo, chunk)])
 
     each_chunk(step, scan)
 
@@ -161,28 +200,51 @@ def list_scan(
     """Squared-L2 distances (B, nprobe, slots) f32 from each query to every
     slot of its probed lists; ``inf`` in chunks that hold no real row.
 
-    VMEM: two buffers of ``group`` whole lists, (group, D, slots) f32 each
-    (DEEP's 96 x 1,536 at group 8: 2 x 4.7 MB; GIST's 960 x 1,792 at group
-    1: 2 x 6.9 MB), and two (group, slots) output blocks, the slots rounded
-    up to 128.
+    ``queries`` and ``list_vecs`` are float32, int8 or uint8, of one type.
+    Rows may be wider than the queries, with zeros past them (8-bit rows,
+    :func:`row_width`); the queries are padded to match.  8-bit rows
+    narrower than :func:`row_width` are padded to it here, which, like a
+    slot count that is not a multiple of 128, copies the lists on every
+    call: hold them padded (``ClusterIndex.device_arrays`` does).
+
+    VMEM: two buffers of ``group`` whole lists in their own type, each
+    (group, D, slots) or, rows on the lanes, (group, slots, D) (DEEP's
+    96 x 1,536 f32 at group 8: 2 x 4.7 MB; GIST's 960 x 1,792 f32 at group
+    1: 2 x 6.9 MB; BIGANN's 3,072 x 128 uint8 at group 8: 2 x 3.1 MB), and
+    two (group, slots) output blocks, the slots rounded up to 128.
     """
-    B, D = queries.shape
-    L, slots, _ = list_vecs.shape
+    B = queries.shape[0]
+    L, slots, D = list_vecs.shape
     nprobe = probe.shape[1]
-    chunk, n_chunks = chunking(slots, D)
-    lanes = -(-slots // _LANE) * _LANE
     itemsize = jnp.dtype(list_vecs.dtype).itemsize
+    if row_width(D, itemsize) != D:
+        list_vecs = jnp.pad(list_vecs, ((0, 0), (0, 0),
+                                        (0, row_width(D, itemsize) - D)))
+        D = list_vecs.shape[2]
+    queries = queries.astype(jnp.float32)
+    if queries.shape[1] < D:
+        queries = jnp.pad(queries, ((0, 0), (0, D - queries.shape[1])))
+    chunk, n_chunks = chunking(slots, D, itemsize)
+    lanes = -(-slots // _LANE) * _LANE
     group = _group(nprobe, D * lanes * itemsize)
     bits = chunk_bits(ids, chunk, n_chunks)
     buf_bytes = 2 * group * D * lanes * itemsize
+    # the bytes as TPU lays them out: (L, slots, D) itself where the rows
+    # fill whole 128-lane tiles, so that nothing pads on the lanes, else
+    # its (L, D, slots) view.  A slot count that is not a multiple of 128
+    # is padded to one, which copies the lists on every call.
+    rows = D % _LANE == 0
     kernel = functools.partial(_scan_kernel, group=group, chunk=chunk,
-                               n_chunks=n_chunks)
-    # (L, D, slots): the bytes as TPU lays them out when the slots are on
-    # the lanes.  A slot count that is not a multiple of 128 is padded to
-    # one, which copies the lists on every call.
-    lists = jnp.swapaxes(list_vecs, 1, 2)
+                               n_chunks=n_chunks, rows=rows)
+    if rows:
+        lists, pad = list_vecs, ((0, 0), (0, lanes - slots), (0, 0))
+        buf = (2, group, lanes, D)
+    else:
+        lists, pad = (jnp.swapaxes(list_vecs, 1, 2),
+                      ((0, 0), (0, 0), (0, lanes - slots)))
+        buf = (2, group, D, lanes)
     if lanes > slots:
-        lists = jnp.pad(lists, ((0, 0), (0, 0), (0, lanes - slots)))
+        lists = jnp.pad(lists, pad)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -196,7 +258,7 @@ def list_scan(
             out_specs=pl.BlockSpec((None, group, lanes),
                                    lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, group, D, lanes), list_vecs.dtype),
+                pltpu.VMEM(buf, list_vecs.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ]),
         out_shape=jax.ShapeDtypeStruct((B * nprobe // group, group, lanes),

@@ -11,13 +11,14 @@ import jax.numpy as jnp
 def l2_distance_ref(q: jax.Array, x: jax.Array) -> jax.Array:
     """Squared L2: q (Q, D), x (N, D) -> (Q, N).
 
-    f32 accumulation for float inputs; exact int32 accumulation for int8.
+    f32 accumulation for float inputs; exact int32 accumulation for int8
+    and uint8, widened to int32 before any product.
     """
-    if q.dtype == jnp.int8:
+    if jnp.issubdtype(q.dtype, jnp.integer):
         qi, xi = q.astype(jnp.int32), x.astype(jnp.int32)
         qn = jnp.sum(qi * qi, axis=-1)[:, None]
         xn = jnp.sum(xi * xi, axis=-1)[None, :]
-        ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+        ip = jax.lax.dot_general(qi, xi, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.int32)
         return (qn + xn - 2 * ip).astype(jnp.float32)
     qf, xf = q.astype(jnp.float32), x.astype(jnp.float32)
@@ -54,8 +55,9 @@ def l2_topk_ref(q: jax.Array, x: jax.Array, k: int
 def list_scan_ref(queries: jax.Array, list_vecs: jax.Array,
                   list_ids: jax.Array, probe: jax.Array) -> jax.Array:
     """Probed-list distances: the gather-then-scan the list-scan kernel
-    replaces.  queries (B, D), list_vecs (L, slots, D), list_ids (L, slots)
-    (-1 pad), probe (B, nprobe) -> (B, nprobe, slots), ``inf`` at padding."""
+    replaces.  queries (B, D), list_vecs (L, slots, D) of the queries' type,
+    list_ids (L, slots) (-1 pad), probe (B, nprobe) -> (B, nprobe, slots),
+    ``inf`` at padding; exact on int8 and uint8."""
     vecs = list_vecs[probe]                          # (B, nprobe, slots, D)
     d = jax.vmap(lambda q, v: l2_distance_ref(q[None], v.reshape(
         -1, v.shape[-1]))[0].reshape(v.shape[:2]))(queries, vecs)

@@ -122,3 +122,65 @@ def test_int8_dataset_build_and_search():
     # int8 posting lists are ~4x smaller than f32 would be
     assert idx.meta.avg_list_bytes < idx.meta.list_lengths.mean() * (
         spec.dim * 4 + 8)
+
+
+def _uint8_corpus(n, nq, dim, seed):
+    """A seeded uint8 corpus and queries: a Gaussian mixture carried onto
+    [0, 255], as the chip benchmark's BIGANN analogue is."""
+    data, queries = make_dataset(scaled(DEEP_ANALOG, n, nq, dim=dim,
+                                        seed=seed))
+    lo, hi = data.min(), data.max()
+
+    def to_u8(x):
+        return np.clip(np.round((x - lo) * 255 / (hi - lo)), 0, 255
+                       ).astype(np.uint8)
+    return to_u8(data), to_u8(queries)
+
+
+@pytest.mark.parametrize("dtype,dim,width", [
+    (np.uint8, 128, 128), (np.int8, 100, 128), (np.float32, 96, 96)])
+def test_device_arrays_keep_the_element_type(dtype, dim, width):
+    # 8-bit lists are held as bytes, padded with zeros to whole lane tiles
+    data = np.random.default_rng(0).integers(
+        -100, 100, size=(600, dim)).astype(dtype)
+    idx = ClusterIndex.build(data, ClusterIndexParams(centroid_frac=0.05,
+                                                      seed=0))
+    arrs = idx.device_arrays()
+    vecs, ids = arrs["list_vecs"], arrs["list_ids"]
+    assert vecs.dtype == dtype and vecs.shape[2] == width
+    assert arrs["centroids"].dtype == np.float32
+    real = ids >= 0
+    np.testing.assert_array_equal(vecs[real][:, :dim], data[ids[real]])
+    assert not vecs[..., dim:].any() and not vecs[~real].any()
+
+
+def test_device_search_uint8_matches_exact_host_search():
+    # every distance an exact integer: the device search returns the ids
+    # and distances of a float64 search over the same probed lists
+    data, queries = _uint8_corpus(1500, 16, 128, seed=3)
+    idx = ClusterIndex.build(data, ClusterIndexParams(centroid_frac=0.04,
+                                                      seed=0))
+    arrs = idx.device_arrays()
+    assert arrs["list_vecs"].dtype == np.uint8
+    nprobe, k = 8, 10
+    ids, dists = device_search_batch(
+        jnp.asarray(arrs["centroids"]), jnp.asarray(arrs["list_vecs"]),
+        jnp.asarray(arrs["list_ids"]), jnp.asarray(queries),
+        nprobe=nprobe, k=k)
+    ids, dists = np.asarray(ids), np.asarray(dists)
+    q64 = queries.astype(np.float64)
+    c64 = arrs["centroids"].astype(np.float64)
+    cd = ((q64[:, None, :] - c64[None, :, :]) ** 2).sum(-1)
+    for i in range(len(queries)):
+        probed = np.argsort(cd[i], kind="stable")[:nprobe]
+        cand = arrs["list_ids"][probed].ravel()
+        cand = np.unique(cand[cand >= 0])
+        exact = ((data[cand].astype(np.int64) - queries[i].astype(np.int64))
+                 ** 2).sum(-1)
+        order = np.lexsort((cand, exact))[:k + 1]
+        np.testing.assert_array_equal(dists[i], exact[order[:k]])
+        served = ((data[ids[i]].astype(np.int64)
+                   - queries[i].astype(np.int64)) ** 2).sum(-1)
+        np.testing.assert_array_equal(served, dists[i])
+        if exact[order[k - 1]] < exact[order[k]]:
+            assert set(ids[i]) == set(cand[order[:k]])

@@ -33,6 +33,21 @@ def test_int8_exact_integer_arithmetic():
     np.testing.assert_array_equal(got.astype(np.int64), want)
 
 
+@pytest.mark.parametrize("dtype", [np.uint8, np.int8])
+def test_integer_queries_against_float_centroids(dtype):
+    # the device search's probe: 8-bit queries widen to f32 (exactly) and
+    # meet the f32 centroids at HIGHEST, as float queries do
+    rng = np.random.default_rng(5)
+    info = np.iinfo(dtype)
+    qs = rng.integers(info.min, info.max + 1, size=(6, 128)).astype(dtype)
+    cs = rng.uniform(info.min, info.max, size=(40, 128)).astype(np.float32)
+    got = np.asarray(pairwise_sq_l2(jnp.asarray(qs), jnp.asarray(cs)))
+    q64, c64 = qs.astype(np.float64), cs.astype(np.float64)
+    want = ((q64[:, None, :] - c64[None, :, :]) ** 2).sum(-1)
+    scale = (q64 ** 2).sum(1)[:, None] + (c64 ** 2).sum(1)[None, :]
+    assert np.abs(got - want).max() / scale.min() < 1e-6
+
+
 def test_neg_ip():
     rng = np.random.default_rng(2)
     qs = rng.normal(size=(5, 32)).astype(np.float32)

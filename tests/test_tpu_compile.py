@@ -19,14 +19,20 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core.cluster_index import SEARCH_STAGES, device_search_batch
 from repro.core.distributed import sharded_search_step
 from repro.core.pq import default_pq_dims
-from repro.kernels import distance, fused_topk, ops, pq_adc
+from repro.kernels import distance, fused_topk, list_scan, ops, pq_adc
 
 #: one v5e chip's HBM
 HBM_BYTES = 16 * 2**30
-#: the chip benchmark's layouts (lists, slots, D) and their batch sizes
+#: the chip benchmark's layouts (lists, slots, D), their batch sizes and
+#: element types, a 100-d int8 one (an MSSPACE10M share, 2M rows) and a
+#: 128-d float32 one, whose rows, a whole lane tile, stay on the lanes
 DEEP_LAYOUT, GIST_LAYOUT = (12_500, 1_536, 96), (1_000, 1_792, 960)
-BULK = [(*DEEP_LAYOUT, 256), (*GIST_LAYOUT, 16)]
-BULK_IDS = ["deep96-f32", "gist960-f32"]
+BULK = [(*DEEP_LAYOUT, 256, jnp.float32), (*GIST_LAYOUT, 16, jnp.float32),
+        (20_000, 1_280, 128, 256, jnp.uint8),
+        (20_000, 3_072, 100, 256, jnp.int8),
+        (8_000, 1_536, 128, 256, jnp.float32)]
+BULK_IDS = ["deep96-f32", "gist960-f32", "bigann128-u8", "msspace100-i8",
+            "sift128-f32"]
 
 
 @pytest.fixture(scope="module")
@@ -57,23 +63,27 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def compile_search(one_chip):
-    """Compiles ``device_search_batch`` at (lists, slots, D, B), nprobe 32,
-    k 10, once a shape.  Off the TPU the kernels would be compiled for the
-    Pallas interpreter: Mosaic is asked for here."""
+    """Compiles ``device_search_batch`` at (lists, slots, D, B) and the
+    element type of the queries and lists, nprobe 32, k 10, once a shape;
+    the lists are as wide as ``ClusterIndex.device_arrays`` holds them.
+    Off the TPU the kernels would be compiled for the Pallas interpreter:
+    Mosaic is asked for here."""
     ops.set_default_interpret(False)
     done = {}
 
-    def compile_at(L, maxlen, D, B):
-        if (L, maxlen, D, B) not in done:
+    def compile_at(L, maxlen, D, B, dtype=jnp.float32):
+        key = (L, maxlen, D, B, jnp.dtype(dtype).name)
+        if key not in done:
             # a fresh callable, so no trace made for the interpreter is reused
             fn = jax.jit(functools.partial(device_search_batch, nprobe=32,
                                            k=10))
-            done[L, maxlen, D, B] = fn.lower(
+            width = list_scan.row_width(D, jnp.dtype(dtype).itemsize)
+            done[key] = fn.lower(
                 _sds((L, D), jnp.float32, one_chip),
-                _sds((L, maxlen, D), jnp.float32, one_chip),
+                _sds((L, maxlen, width), dtype, one_chip),
                 _sds((L, maxlen), jnp.int32, one_chip),
-                _sds((B, D), jnp.float32, one_chip)).compile()
-        return done[L, maxlen, D, B]
+                _sds((B, D), dtype, one_chip)).compile()
+        return done[key]
     yield compile_at
     ops.set_default_interpret(None)
 
@@ -124,12 +134,13 @@ def test_device_search_batch_compiles_at_deep_1m(compile_search, maxlen):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
-@pytest.mark.parametrize("L,maxlen,D,B", BULK, ids=BULK_IDS)
+@pytest.mark.parametrize("L,maxlen,D,B,dtype", BULK, ids=BULK_IDS)
 def test_device_search_batch_scans_lists_in_place(compile_search,
-                                                  L, maxlen, D, B):
+                                                  L, maxlen, D, B, dtype):
     # the list-scan kernel reads the resident lists: no (B, nprobe, slots,
-    # D) copy and no relayout of the corpus takes device memory
-    c = compile_search(L, maxlen, D, B)
+    # D) copy and no relayout, widening or copy of the corpus takes device
+    # memory
+    c = compile_search(L, maxlen, D, B, dtype)
     assert _has_kernel(c)
     assert c.memory_analysis().temp_size_in_bytes < 2**30
 
@@ -152,11 +163,12 @@ def _first_scopes(hlo_text: str) -> list:
     return out
 
 
-@pytest.mark.parametrize("L,maxlen,D,B", BULK, ids=BULK_IDS)
-def test_device_search_batch_names_its_stages(compile_search, L, maxlen, D, B):
+@pytest.mark.parametrize("L,maxlen,D,B,dtype", BULK, ids=BULK_IDS)
+def test_device_search_batch_names_its_stages(compile_search, L, maxlen, D, B,
+                                              dtype):
     # the layouts of the chip benchmark's cells, at their batch sizes: every
     # op the TPU compiler keeps metadata for is in one of the four stages
-    text = compile_search(L, maxlen, D, B).as_text()
+    text = compile_search(L, maxlen, D, B, dtype).as_text()
     scopes = set(_first_scopes(text))
     assert scopes == set(SEARCH_STAGES), sorted(map(str, scopes))
 
